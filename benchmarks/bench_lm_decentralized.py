@@ -40,6 +40,8 @@ _WORKER_TIMEOUT = 900
 def run(quick: bool = False) -> None:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    # fake CPU devices by design: never ask for a chip the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "benchmarks.bench_lm_decentralized",
            "--worker"] + (["--quick"] if quick else [])
     p = subprocess.run(cmd, capture_output=True, text=True, env=env,

@@ -13,7 +13,7 @@ from repro.configs.base import AveragingConfig, GovernorConfig, StreamConfig
 from repro.configs.paper_pca import FIG7, PCARunConfig
 from repro.core import krasulina, problems
 from repro.data.synthetic import make_pca_host_sampler, make_pca_stream
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.train.driver import EngineConfig, StreamingDriver
 
 stream = make_pca_stream(FIG7)
@@ -98,6 +98,6 @@ sched = mixing.schedule("ring", N)
 zn = stream.draw(jax.random.PRNGKey(3), 40).reshape(N, 4, -1)
 wn = jnp.tile(w0[None], (N, 1))
 h_kernel = ops.krasulina_xi_gossip(wn, zn, sched, 4, force_pallas=True)
-h_ref = ops.krasulina_xi_gossip(wn, zn, sched, 4)
+h_ref = ref.krasulina_xi_gossip_ref(wn, zn, sched, 4)
 print(f"Pallas xi+gossip kernel max |h - ref| = "
       f"{float(jnp.max(jnp.abs(h_kernel - h_ref))):.2e}")

@@ -51,7 +51,7 @@ def _kernel(x_ref, o_ref, *, shifts: Tuple[int, ...], weights: Tuple[float, ...]
                                     "interpret"))
 def gossip_mix_pallas(x: jax.Array, shifts: Tuple[int, ...],
                       weights: Tuple[float, ...], rounds: int, *,
-                      block_d: int = 512, interpret: bool = True) -> jax.Array:
+                      block_d: int = 512, interpret: bool) -> jax.Array:
     """R rounds of `sum_s w_s * roll(x, s, axis=0)` in a single HBM pass.
 
     x: [n, ...] (any rank; trailing dims are flattened). shifts/weights: the
@@ -122,7 +122,7 @@ def gossip_mix_quant_pallas(x: jax.Array, shifts: Tuple[int, ...],
                             weights: Tuple[float, ...], rounds: int,
                             quant: str, *, block_d: int = 512,
                             valid_d: int = -1,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool) -> jax.Array:
     """R rounds of quantized gossip (tile-statistics compressors) in a single
     HBM pass. quant: "sign" | "int8" (deterministic — the stochastic variant
     stays on the XLA path). `valid_d`: flattened columns >= valid_d are pad
@@ -243,13 +243,21 @@ def shard_compatible(sched, n: int, extent: int) -> bool:
     return ru + rd <= n - n // extent
 
 
+def _lane_dense(n_local: int, size: int) -> Tuple[int, ...]:
+    """Shape for a shard's [n_local, D] rows in the exchange: [n_local,
+    D/128, 128] when it divides, so a shard holding one node's row is not
+    padded along its sublanes (a [1, D] bf16 buffer takes twice its size on
+    a TPU), else [n_local, D]."""
+    d = size // n_local
+    return (n_local, d // 128, 128) if d % 128 == 0 else (n_local, d)
+
+
 def gossip_mix_shard(x: jax.Array, sched, rounds: int, mesh,
                      node_axes: Tuple[str, ...], axis: str) -> jax.Array:
     """R rounds of circulant gossip over a node axis sharded across
     `node_axes` of `mesh` (`axis`: the single nontrivial one the ppermute ring
     runs over). Per-round halo exchange + fused local slice-sum; bit-identical
     to `ref.gossip_mix_ref`."""
-    from jax.experimental import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = x.shape[0]
@@ -260,15 +268,15 @@ def gossip_mix_shard(x: jax.Array, sched, rounds: int, mesh,
 
     def local(h):
         shape = h.shape
-        h = h.reshape(n_local, -1)
+        h = h.reshape(_lane_dense(n_local, h.size))
         for _ in range(rounds):
             ext = _ext_tile(h, ru, rd, axis, extent, n_local)
             h = _slice_round(ext, sched, n, ru, n_local)
         return h.reshape(shape)
 
     spec = P(node_axes, *([None] * (x.ndim - 1)))
-    return shard_map.shard_map(local, mesh=mesh, in_specs=spec,
-                               out_specs=spec)(x)
+    return jax.shard_map(local, mesh=mesh, in_specs=spec,
+                         out_specs=spec)(x)
 
 
 def gossip_mix_quant_shard(x: jax.Array, sched, rounds: int, quant: str,
@@ -286,7 +294,6 @@ def gossip_mix_quant_shard(x: jax.Array, sched, rounds: int, quant: str,
     Stochastic compressors fold the shard index into the key (deterministic,
     but layout-dependent noise — sign/int8 are layout-invariant)."""
     from repro.core.quantize import STOCHASTIC, tile_compress
-    from jax.experimental import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = x.shape[0]
@@ -311,5 +318,5 @@ def gossip_mix_quant_shard(x: jax.Array, sched, rounds: int, quant: str,
         return h.reshape(shape).astype(x.dtype)
 
     spec = P(node_axes, *([None] * (x.ndim - 1)))
-    return shard_map.shard_map(local, mesh=mesh, in_specs=spec,
-                               out_specs=spec)(x)
+    return jax.shard_map(local, mesh=mesh, in_specs=spec,
+                         out_specs=spec)(x)

@@ -92,7 +92,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0, chunk: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q/k/v: [B, H, S, D] (GQA heads pre-broadcast). Returns [B, H, Sq, D]."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]

@@ -45,19 +45,19 @@ def _kernel(w_ref, z_ref, o_ref, acc_ref, s2_ref, *, n_tiles: int, batch: int):
                             preferred_element_type=jnp.float32)  # [tb, 1]
     acc_ref[...] += jax.lax.dot_general(s, z, (((0,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32)  # [1, d]
-    s2_ref[0, 0] += jnp.sum(s * s)
+    s2_ref[...] += jnp.sum(s * s, keepdims=True)  # [1, 1]: Mosaic stores vectors
 
     @pl.when(t == n_tiles - 1)
     def _epilogue():
         wf = w_ref[...].astype(jnp.float32)
         nrm2 = jnp.maximum(jnp.sum(wf * wf), 1e-30)
-        mean_s2 = s2_ref[0, 0] / batch
+        mean_s2 = s2_ref[...] / batch  # [1, 1], broadcasts over d
         o_ref[...] = (acc_ref[...] / batch - (mean_s2 / nrm2) * wf).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def krasulina_xi_pallas(w: jax.Array, z: jax.Array, *, block_b: int = 256,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """w: [d]; z: [B, d] -> xi [d]. Pads B up to a multiple of block_b (zero rows
     contribute nothing to either accumulator, but the mean uses the true B)."""
     B, d = z.shape
@@ -83,16 +83,20 @@ def krasulina_xi_pallas(w: jax.Array, z: jax.Array, *, block_b: int = 256,
     return out[0]
 
 
-def _xi_gossip_kernel(w_ref, z_ref, o_ref, s_ref, nrm2_ref, *,
+def _xi_gossip_kernel(w_ref, z_ref, o_ref, s_ref, nrm2_ref, h_ref, *,
                       shifts: Tuple[int, ...], weights: Tuple[float, ...],
                       rounds: int, batch_n: int):
     """Grid (2, n_tiles). Phase 0 accumulates the full-d reductions (s = Z w
     per node, ||w||^2 per node) tile by tile; phase 1 revisits each tile,
     forms the xi column block for all N nodes and runs every gossip round on
-    the resident [N, block_d] tile before the one write-back."""
+    the resident [N, block_d] tile before the one write-back.
+
+    The per-node contractions are multiply-and-reduce on the VPU, one node at
+    a time over 2-D [Bn, block_d] slices: Mosaic has no node-batched
+    `dot_general`, and a matvec wastes the MXU anyway (the op is
+    memory-bound)."""
     p, t = pl.program_id(0), pl.program_id(1)
-    w = w_ref[...].astype(jnp.float32)  # [N, bd]
-    z = z_ref[...].astype(jnp.float32)  # [N, Bn, bd]
+    n = w_ref.shape[0]
 
     @pl.when(p == 0)
     def _accumulate():
@@ -101,21 +105,24 @@ def _xi_gossip_kernel(w_ref, z_ref, o_ref, s_ref, nrm2_ref, *,
             s_ref[...] = jnp.zeros_like(s_ref)
             nrm2_ref[...] = jnp.zeros_like(nrm2_ref)
 
-        # s_n += Z_n[:, tile] @ w_n[tile]  (batched over the node axis)
-        s_ref[...] += jax.lax.dot_general(
-            z, w, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)  # [N, Bn]
+        w = w_ref[...].astype(jnp.float32)  # [N, bd]
         nrm2_ref[...] += jnp.sum(w * w, axis=1, keepdims=True)  # [N, 1]
+        for i in range(n):  # s_n += Z_n[:, tile] w_n[tile], as a [Bn, 1] column
+            z = z_ref[i].astype(jnp.float32)  # [Bn, bd]
+            s_ref[i] += jnp.sum(z * w[i:i + 1], axis=1, keepdims=True)
 
     @pl.when(p == 1)
     def _xi_and_gossip():
-        s = s_ref[...]  # [N, Bn], complete after phase 0
-        nrm2 = jnp.maximum(nrm2_ref[...], 1e-30)  # [N, 1]
-        coeff = jnp.sum(s * s, axis=1, keepdims=True) / (batch_n * nrm2)
-        # xi tile: (1/Bn) Z^T s - (mean(s^2)/||w||^2) w, all nodes at once
-        zts = jax.lax.dot_general(s, z, (((1,), (1,)), ((0,), (0,))),
-                                  preferred_element_type=jnp.float32)  # [N, bd]
-        h = zts / batch_n - coeff * w
+        w = w_ref[...].astype(jnp.float32)  # [N, bd]
+        nrm2 = jnp.maximum(nrm2_ref[...], 1e-30)  # [N, 1], complete after phase 0
+        for i in range(n):
+            # xi_n tile: (1/Bn) Z_n^T s_n - (mean(s_n^2)/||w_n||^2) w_n
+            s = s_ref[i]  # [Bn, 1]
+            z = z_ref[i].astype(jnp.float32)  # [Bn, bd]
+            zts = jnp.sum(s * z, axis=0, keepdims=True)  # [1, bd]
+            coeff = jnp.sum(s * s, keepdims=True) / (batch_n * nrm2[i:i + 1])
+            h_ref[i:i + 1, :] = zts / batch_n - coeff * w[i:i + 1]
+        h = h_ref[...]  # [N, bd]
         for _ in range(rounds):
             acc = None
             for sh, wt in zip(shifts, weights):
@@ -133,7 +140,7 @@ def krasulina_xi_gossip_pallas(w: jax.Array, z: jax.Array,
                                shifts: Tuple[int, ...],
                                weights: Tuple[float, ...], rounds: int, *,
                                block_d: int = 512,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool) -> jax.Array:
     """w: [N, d] per-node iterates; z: [N, Bn, d] per-node mini-batches ->
     [N, d] gossip-mixed pseudo-gradients: R rounds of
     `sum_s w_s * roll(xi, s, axis=0)` applied to xi_n = krasulina_xi(w_n, z_n).
@@ -141,7 +148,7 @@ def krasulina_xi_gossip_pallas(w: jax.Array, z: jax.Array,
     Pads d up to a multiple of block_d (zero columns contribute nothing to
     s/||w||^2 and stay zero through the rolls). The whole [N, Bn] s-matrix is
     kept in VMEM scratch, so Bn is assumed streaming-small (B/N per the
-    splitter), not a full epoch."""
+    splitter), not a full epoch; the node loop is unrolled, so N is too."""
     n, bn, d = z.shape
     assert w.shape == (n, d), (w.shape, z.shape)
     shifts = tuple(int(s) % n for s in shifts)
@@ -162,8 +169,9 @@ def krasulina_xi_gossip_pallas(w: jax.Array, z: jax.Array,
         out_specs=pl.BlockSpec((n, block_d), lambda p, t: (0, t)),
         out_shape=jax.ShapeDtypeStruct((n, n_tiles * block_d), w.dtype),
         scratch_shapes=[
-            pltpu.VMEM((n, bn), jnp.float32),
-            pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((n, bn, 1), jnp.float32),  # s = Z w per node
+            pltpu.VMEM((n, 1), jnp.float32),  # ||w||^2 per node
+            pltpu.VMEM((n, block_d), jnp.float32),  # xi tile before gossip
         ],
         interpret=interpret,
     )(w, z)
@@ -188,7 +196,6 @@ def krasulina_xi_gossip_shard(w: jax.Array, z: jax.Array, sched, rounds: int,
     oracle `ref.gossip_mix_ref(vmap(ref.krasulina_xi_ref), ...)` to f32
     round-off (xi itself is shard-invariant bitwise).
     w: [n, d], z: [n, B, d], both sharded on the node axis."""
-    from jax.experimental import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels import ref
@@ -209,5 +216,5 @@ def krasulina_xi_gossip_shard(w: jax.Array, z: jax.Array, sched, rounds: int,
 
     wspec = P(node_axes, None)
     zspec = P(node_axes, None, None)
-    return shard_map.shard_map(local, mesh=mesh, in_specs=(wspec, zspec),
-                               out_specs=wspec)(w, z)
+    return jax.shard_map(local, mesh=mesh, in_specs=(wspec, zspec),
+                         out_specs=wspec)(w, z)

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 """Multi-pod dry-run: prove the distribution config is coherent without
 hardware.
 
@@ -7,10 +8,12 @@ For every (architecture x input-shape x mesh) this lowers + compiles the real
 train/prefill/serve step against ShapeDtypeStruct stand-ins (no allocation),
 prints memory_analysis() (fits per chip?) and cost_analysis() (FLOPs/bytes for
 the roofline), parses the optimized HLO for collective bytes, and writes a JSON
-artifact consumed by repro.roofline and EXPERIMENTS.md.
+artifact consumed by repro.roofline.
 
-NOTE the two lines above: jax locks the device count at first init, so the
-XLA_FLAGS export precedes every import, including `from repro...`.
+NOTE the three lines above: jax locks the device count at first init, so the
+XLA_FLAGS export precedes every import, including `from repro...`. The 512
+devices are fake CPU ones by design, so the CPU platform is pinned: on a TPU
+host the dry-run must not take the chip (or see only the 1-4 real ones).
 """
 import argparse
 import json
@@ -63,12 +66,8 @@ _UPCAST_RE = re.compile(r"\(param_[\w.]+: bf16\[([\d,]+)\]\) -> f32\[")
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Version-portable `compiled.cost_analysis()`: jax <= 0.4.x returns a
-    per-device list of dicts, newer jax a single dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """`compiled.cost_analysis()`, empty when the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def parse_cpu_upcasts(hlo: str) -> float:

@@ -1,7 +1,7 @@
-"""Launcher-level perf hygiene: process environment the XLA runtime reads at
-import time (tcmalloc preload detection, XLA step-marker flags, TF log
-noise), applied by `launch/train.py` and `launch/serve.py` BEFORE `import
-jax`.
+"""Launcher-level process environment the XLA runtime reads at import time
+(tcmalloc preload detection, TF log noise, the persistent compilation
+cache), applied by `launch/train.py`, `launch/serve.py` and `chip_smoke.py`
+BEFORE `import jax`.
 
 This module must therefore stay import-light: no jax, no repro modules that
 pull jax in. Everything is pure env-dict manipulation so it is unit-testable
@@ -9,12 +9,14 @@ without touching the real process environment.
 
 Escape hatch: pass `--no-env-tuning` on any launcher command line (peeked
 from argv before argparse runs, because the tuning must land before the jax
-import that argparse-time application would be too late for).
+import that argparse-time application would be too late for). It skips the
+tuning only; the compilation cache is always wired.
 """
 from __future__ import annotations
 
 import os
 import sys
+from pathlib import Path
 from typing import Dict, List, Optional
 
 # Well-known tcmalloc locations (Debian/Ubuntu package paths). Preloading
@@ -32,32 +34,8 @@ TCMALLOC_PATHS = (
 # staging buffers trip the default 1 GiB threshold constantly).
 TCMALLOC_REPORT_THRESHOLD = "60000000000"
 
-# --xla_step_marker_location=1: mark the outer while loop (the K-round
-# superstep scan) as the step boundary for profiler alignment; 0 would mark
-# the program entry. TPU-only: CPU/GPU XLA builds do not register the flag
-# and hard-fail ("Check failed: Flags::Parse") on any unknown XLA_FLAGS
-# entry, so it is injected only when a TPU runtime is detectable.
-XLA_STEP_MARKER = "--xla_step_marker_location=1"
-
-
-def tpu_available(env: Optional[Dict[str, str]] = None) -> bool:
-    """Best-effort TPU detection WITHOUT importing jax (this module runs
-    before the jax import). An explicit platform request (JAX_PLATFORMS /
-    JAX_PLATFORM_NAME) is authoritative — a toolchain image can ship libtpu
-    while pinning the cpu backend, whose XLA client rejects TPU flags.
-    Without one, a libtpu install or a /dev accel device means jax will
-    initialize the TPU plugin."""
-    env = os.environ if env is None else env
-    plat = env.get("JAX_PLATFORMS", env.get("JAX_PLATFORM_NAME", ""))
-    if plat:
-        return "tpu" in plat
-    try:
-        import importlib.util
-        if importlib.util.find_spec("libtpu") is not None:
-            return True
-    except (ImportError, ValueError):
-        pass
-    return any(os.path.exists(f"/dev/accel{i}") for i in range(4))
+# The checkout root (src/repro/launch/env.py -> three levels up from src/).
+CHECKOUT = Path(__file__).resolve().parents[3]
 
 
 def find_tcmalloc() -> Optional[str]:
@@ -68,29 +46,23 @@ def find_tcmalloc() -> Optional[str]:
     return None
 
 
-def tuned_env(env: Optional[Dict[str, str]] = None,
-              tpu: Optional[bool] = None) -> Dict[str, str]:
+def tuned_env(env: Optional[Dict[str, str]] = None) -> Dict[str, str]:
     """Return the perf-hygiene mutations as a dict (pure; does not apply).
 
     * TF_CPP_MIN_LOG_LEVEL=4 — silence TF/XLA C++ info spam on the hot path
       (only if the user has not chosen a level).
-    * XLA_FLAGS gains the step-marker flag on TPU runtimes (idempotent:
-      never duplicated, user-provided flags preserved; CPU/GPU XLA rejects
-      unknown flags outright, so non-TPU backends are left untouched).
     * TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD raised (if unset).
     * LD_PRELOAD set to a detected tcmalloc (if unset and one exists) so
       *child* processes get it; the current process is unaffected.
 
-    `tpu` overrides the runtime detection (tests); None = auto-detect.
+    XLA_FLAGS is left alone: jaxlib parses it once for every backend in the
+    process and aborts on a flag it does not register, so a TPU-only flag
+    there kills the host-side CPU client too.
     """
     env = dict(os.environ if env is None else env)
     out: Dict[str, str] = {}
     if "TF_CPP_MIN_LOG_LEVEL" not in env:
         out["TF_CPP_MIN_LOG_LEVEL"] = "4"
-    xla = env.get("XLA_FLAGS", "")
-    tpu = tpu_available(env) if tpu is None else tpu
-    if tpu and "--xla_step_marker_location" not in xla:
-        out["XLA_FLAGS"] = f"{XLA_STEP_MARKER} {xla}".strip()
     if "TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD" not in env:
         out["TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD"] = TCMALLOC_REPORT_THRESHOLD
     tc = find_tcmalloc()
@@ -109,43 +81,26 @@ def wants_tuning(argv: Optional[List[str]] = None) -> bool:
 # Persistent compilation cache (docs/DESIGN.md §Fault-tolerant streaming)
 # ---------------------------------------------------------------------------
 
-def compilation_cache_env(cache_dir: str) -> Dict[str, str]:
-    """Env mutations enabling jax's persistent compilation cache at
-    `cache_dir` (pure; must land before `import jax`). Opt-in: a restarted
-    run re-traces every (B, cohort) bucket-ladder signature, and without the
-    cache each retrace pays a cold XLA compile — with it, restart cost is a
-    disk hit per signature. Thresholds are zeroed so even the small
-    supersteps of tests/benchmarks are cached (jax's defaults skip
-    sub-second compiles)."""
-    return {"JAX_COMPILATION_CACHE_DIR": cache_dir,
-            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
-            "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1"}
+# jax's defaults skip sub-second compiles and small entries; zeroed, the
+# small supersteps of tests and benchmarks are cached too
+CACHE_THRESHOLDS = {"JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+                    "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1"}
 
 
-def compilation_cache_dir_from_argv(argv: Optional[List[str]] = None
-                                    ) -> Optional[str]:
-    """Peek `--compilation-cache-dir PATH` (or `=PATH`) from raw argv —
-    pre-argparse, because the cache location must be in the environment
-    before the jax import that argparse-time application would be too late
-    for."""
-    argv = sys.argv if argv is None else argv
-    for i, a in enumerate(argv):
-        if a == "--compilation-cache-dir" and i + 1 < len(argv):
-            return argv[i + 1]
-        if a.startswith("--compilation-cache-dir="):
-            return a.split("=", 1)[1]
-    return None
-
-
-def enable_compilation_cache(cache_dir: str) -> None:
-    """In-process variant for code running after `import jax` (tests, the
-    kill-and-resume workers): point the live jax config at `cache_dir` with
-    the same zeroed thresholds."""
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+def compilation_cache_env(env: Optional[Dict[str, str]] = None
+                          ) -> Dict[str, str]:
+    """The one rule for where compiled executables persist (pure; must land
+    before `import jax`). A JAX_COMPILATION_CACHE_DIR the caller set is the
+    cache, and no other directory is set. Otherwise the cache is the fixed
+    `<checkout>/.jax_cache`: never a temporary or per-run path, since the
+    directory is what a later run looks up. Thresholds the caller did not
+    set are zeroed. A restarted run then pays a disk hit per (B, cohort)
+    signature instead of a compile."""
+    env = os.environ if env is None else env
+    out = {k: v for k, v in CACHE_THRESHOLDS.items() if k not in env}
+    if not env.get("JAX_COMPILATION_CACHE_DIR"):
+        out["JAX_COMPILATION_CACHE_DIR"] = str(CHECKOUT / ".jax_cache")
+    return out
 
 
 def apply(env: Optional[Dict[str, str]] = None, *, echo: bool = False) -> Dict[str, str]:
@@ -168,17 +123,11 @@ def apply(env: Optional[Dict[str, str]] = None, *, echo: bool = False) -> Dict[s
 
 
 def apply_from_argv(argv: Optional[List[str]] = None) -> Dict[str, str]:
-    """What launcher modules call at import time, before `import jax`:
-    apply tuning unless `--no-env-tuning` is on the command line, and wire
-    the persistent compilation cache when `--compilation-cache-dir` is.
-    The cache is independent of the tuning escape hatch — it is opt-in via
-    its own flag, not perf hygiene."""
-    changes: Dict[str, str] = {}
-    cache_dir = compilation_cache_dir_from_argv(argv)
-    if cache_dir is not None:
-        cc = compilation_cache_env(cache_dir)
-        os.environ.update(cc)
-        changes.update(cc)
+    """What launcher modules call at import time, before `import jax`: wire
+    the compilation cache, and apply the tuning unless `--no-env-tuning` is
+    on the command line."""
+    changes = compilation_cache_env()
+    os.environ.update(changes)
     if wants_tuning(argv):
         changes.update(apply(echo=False))
     return changes

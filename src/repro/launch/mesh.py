@@ -8,19 +8,10 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """`axis_types` for jax.make_mesh where the installed jax supports it.
-    The pinned jax (0.4.37) predates `jax.sharding.AxisType`; auto sharding
-    is the implicit (and only) behavior there, so the kwarg is omitted."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_mesh(shape: tuple, axes: tuple):
-    """jax.make_mesh with version-portable Auto axis types."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+def make_mesh(shape: tuple, axes: tuple, devices=None):
+    """jax.make_mesh with Auto (propagated) sharding on every axis."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,11 +20,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_host_mesh(model: int = 1):
-    """Whatever this host actually has — for smoke-scale integration tests."""
-    n = len(jax.devices())
-    assert n % model == 0
-    return make_mesh((n // model, model), ("data", "model"))
+def make_host_mesh(model: int = 1, n_devices: int = 0):
+    """A (data, model) mesh over the first `n_devices` local devices, or all
+    of them with 0: a one-chip run on a host that shows several asks for 1."""
+    devices = jax.devices()
+    if n_devices:
+        if n_devices > len(devices):
+            raise ValueError(f"asked for {n_devices} devices, "
+                             f"{len(devices)} visible")
+        devices = devices[:n_devices]
+    n = len(devices)
+    if n % model:
+        raise ValueError(f"{n} devices do not split into model={model}")
+    return make_mesh((n // model, model), ("data", "model"), devices=devices)
 
 
 def data_axes(mesh) -> tuple:

@@ -2,9 +2,11 @@
 (`train.driver`): K-round device scans, async device prefetch, and the
 closed-loop (B, mu) governor.
 
-On real hardware this runs the full assigned config on the production mesh; on
-this CPU container use --reduced to train the family-faithful reduced variant
-end-to-end (the full configs are exercised via launch.dryrun).
+`--layers N` cuts depth only: the model keeps its published widths, which is
+how one chip holds a granite-8b node (`chip_smoke.py`). `--reduced` swaps in
+the family-faithful smoke variant (narrow widths) for CPU runs; the full
+configs are exercised via launch.dryrun. `main(argv)` returns what it built,
+so a script can drive it in-process.
 
 Example:
   PYTHONPATH=src python -m repro.launch.train --arch granite-8b --reduced \
@@ -14,6 +16,7 @@ Example:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 # perf hygiene BEFORE the jax import (XLA reads XLA_FLAGS / TF log level at
 # import time); `--no-env-tuning` on the command line skips it
@@ -34,14 +37,23 @@ from repro.launch.mesh import make_host_mesh, make_production_mesh, n_data_nodes
 from repro.models.common import mesh_rules
 from repro.train import checkpoint as ckpt
 from repro.train.driver import EngineConfig, StreamingDriver
-from repro.train.trainer import (init_state, replicate_for_nodes,
-                                 superstep_builder)
+from repro.train.trainer import init_placed_state, superstep_builder
 
 
-def main():
+def main(argv=None):
+    """Parse `argv` (sys.argv[1:] when None), train, and return
+    `(state, history, publisher)`: the final TrainState, the driver's
+    per-superstep records, and the SnapshotPublisher (None without
+    --publish). Raises when the snapshotter recorded a failed save."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut depth to this many layers, keeping the "
+                         "published widths (0 = the config's depth)")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="build the host mesh over the first N local "
+                         "devices (0 = all visible)")
     ap.add_argument("--steps", type=int, default=100,
                     help="total rounds (rounded up to whole supersteps)")
     ap.add_argument("--batch", type=int, default=8)
@@ -55,7 +67,9 @@ def main():
     ap.add_argument("--streaming-rate", type=float, default=0.0)
     ap.add_argument("--processing-rate", type=float, default=0.0)
     ap.add_argument("--comms-rate", type=float, default=0.0)
-    ap.add_argument("--dtype", default="float32")
+    ap.add_argument("--dtype", default="bfloat16",
+                    help="parameter dtype; below float32 the optimizer keeps "
+                         "float32 master weights")
     ap.add_argument("--checkpoint", default="",
                     help="checkpoint directory; with --checkpoint-every 0 a "
                          "single end-of-run save, otherwise the root for "
@@ -72,10 +86,6 @@ def main():
     ap.add_argument("--resume", default="",
                     help="resume from this checkpoint root (newest valid "
                          "step) or a specific step_NNNNNNNN directory")
-    ap.add_argument("--compilation-cache-dir", default="",
-                    help="persistent XLA compilation cache directory so a "
-                         "resumed run skips recompiles (launch/env.py); "
-                         "applied at import time, declared here for --help")
     ap.add_argument("--log-every", type=int, default=1,
                     help="log every this many supersteps")
     ap.add_argument("--superstep", type=int, default=8,
@@ -135,20 +145,21 @@ def main():
     ap.add_argument("--publish-budget", type=float, default=0.05,
                     help="publish-governor overhead budget: max fraction of "
                          "train wall time spent on snapshot copies")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)
-    mesh = make_production_mesh() if args.production_mesh else make_host_mesh()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    mesh = (make_production_mesh() if args.production_mesh
+            else make_host_mesh(n_devices=args.devices))
     n_nodes = n_data_nodes(mesh)
     scenario = None
     averaging = AveragingConfig(args.averaging, args.rounds, args.topology)
     if args.scenario:
         if args.averaging != "gossip":
             ap.error("--scenario needs --averaging gossip")
-        import dataclasses
-
         from repro.core import scenarios as scenario_lib
 
         scenario = scenario_lib.get_scenario(args.scenario)
@@ -216,9 +227,7 @@ def main():
                                      overhead_budget=args.checkpoint_budget)
 
     with mesh_rules(mesh, rules):
-        state = init_state(run, jax.random.PRNGKey(run.seed))
-        if decentralized:
-            state = replicate_for_nodes(state, n_nodes)
+        state = init_placed_state(run, mesh, jax.random.PRNGKey(run.seed))
         with StreamingDriver(run, mesh, state, sample_fn, engine=engine,
                              superstep_builder=builder,
                              batch=args.batch, faults=faults,
@@ -258,10 +267,15 @@ def main():
               f"failures={st.failures} "
               f"cost_ewma={st.cost_ewma_s * 1e3:.2f}ms "
               f"total_cost={st.total_cost_s:.3f}s -> {args.checkpoint}")
+        if st.failures:
+            raise RuntimeError(f"{st.failures} snapshot save(s) failed, "
+                               f"last: {st.last_error}")
     elif args.checkpoint:
         ckpt.save(args.checkpoint, state, step=supersteps * engine.superstep,
-                  meta={"arch": args.arch, "reduced": args.reduced})
+                  meta={"arch": args.arch, "reduced": args.reduced,
+                        "layers": cfg.num_layers})
         print(f"checkpoint -> {args.checkpoint}")
+    return state, history, publisher
 
 
 def _log(rec):

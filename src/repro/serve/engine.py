@@ -66,15 +66,20 @@ def serve_step(params, cfg: ModelConfig, state: ServeState, *,
 
 def generate(params, cfg: ModelConfig, prompt: Dict[str, jax.Array], max_len: int,
              steps: int, *, dtype=jnp.bfloat16, window_override: int = 0) -> jax.Array:
-    """Simple eager generate loop (examples / tests)."""
+    """Simple static-batch greedy generate loop (examples / tests): one
+    prefill, then `steps - 1` serve_steps. Params are jit arguments, not
+    closed-over constants: folded into the program, a model's weights make
+    the compile take minutes and the executable as large as the model."""
     B = prompt["tokens"].shape[0]
     st = init_serve(cfg, B, max_len, dtype, window_override=window_override)
-    st = prefill(params, cfg, prompt, st, window_override=window_override)
+    st = jax.jit(lambda p, b, s: prefill(p, cfg, b, s,
+                                         window_override=window_override)
+                 )(params, prompt, st)
     toks = [st.last_tokens]
-    step = jax.jit(lambda s: serve_step(params, cfg, s,
-                                        window_override=window_override))
+    step = jax.jit(lambda p, s: serve_step(p, cfg, s,
+                                           window_override=window_override))
     for _ in range(steps - 1):
-        st, t = step(st)
+        st, t = step(params, st)
         toks.append(t)
     return jnp.concatenate(toks, axis=1)
 

@@ -323,9 +323,22 @@ class StreamingDriver:
                 raw = self._builder(p.B)
             if partial_cohort:
                 raw = elastic_superstep(raw, self.n_nodes)
-            fn = jax.jit(raw, donate_argnums=self._donate)
+            fn = jax.jit(raw, donate_argnums=self._donate,
+                         out_shardings=(self._state_shardings(), None))
             self._compiled[(p.B, m)] = fn
         return fn
+
+    def _state_shardings(self):
+        """Pin the superstep's output state to the layout the state was
+        placed in on a mesh. Left to propagation, XLA may return an
+        equivalent but differently spelled layout, and the next superstep
+        then recompiles and cannot reuse the donated buffers. None (let XLA
+        choose) when the state carries no mesh layout."""
+        leaves = jax.tree.leaves(self.state)
+        if leaves and all(isinstance(getattr(a, "sharding", None),
+                                     NamedSharding) for a in leaves):
+            return jax.tree.map(lambda a: a.sharding, self.state)
+        return None
 
     def _ids_for(self, mem: Membership) -> jax.Array:
         ids = self._ids_cache.get(mem)

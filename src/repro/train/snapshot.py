@@ -127,7 +127,7 @@ def restore_driver(driver, root_or_path: str) -> str:
     objects — cohort ladders, compiled supersteps, ids caches — are NOT in
     the snapshot and are rebuilt lazily, exactly as the uninterrupted run
     built them (with a persistent compilation cache, re-compiles become
-    cache hits; see `launch.env.enable_compilation_cache`)."""
+    cache hits; see `launch.env.compilation_cache_env`)."""
     if checkpoint.list_steps(root_or_path):
         path = checkpoint.newest_valid(root_or_path)
         if path is None:

@@ -62,6 +62,24 @@ def replicate_for_nodes(state: TrainState, n_nodes: int) -> TrainState:
                         state)
 
 
+def init_placed_state(run: RunConfig, mesh, key) -> TrainState:
+    """`init_state`, plus `replicate_for_nodes` over the mesh's data nodes in
+    decentralized mode, built directly in the trainer's state layout on
+    `mesh`: each device materializes only its own shards, so N node replicas
+    never pass through one device's memory. Call under `mesh_rules`."""
+    decentralized = run.averaging.mode != "exact"
+    n_nodes = n_data_nodes(mesh)
+
+    def build(k):
+        state = init_state(run, k)
+        return replicate_for_nodes(state, n_nodes) if decentralized else state
+
+    shapes = jax.eval_shape(build, key)
+    specs = _state_specs(shapes, run=run, mesh=mesh,
+                         node_axes=data_axes(mesh) if decentralized else None)
+    return jax.jit(build, out_shardings=shlib.named(specs, mesh))(key)
+
+
 def publish_extract(n_nodes: Optional[int] = None) -> Callable:
     """Extract fn for `serve.publisher.SnapshotPublisher`: map the live
     TrainState to the params a serving replica should load.

@@ -38,7 +38,8 @@ def main() -> None:
         # must land before the jax import below
         from repro.launch import env as _env
 
-        os.environ.update(_env.compilation_cache_env(args.cache_dir))
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = args.cache_dir
+        os.environ.update(_env.compilation_cache_env())
 
     import jax
     import jax.numpy as jnp

@@ -189,7 +189,8 @@ def test_quant_gossip_kernel_valid_d_masks_pad_columns():
 
 def test_quant_kernel_rejects_stochastic():
     with pytest.raises(ValueError):
-        gossip_mix_quant_pallas(_x(4, 8), (0, 1), (0.5, 0.5), 1, "int8_stoch")
+        gossip_mix_quant_pallas(_x(4, 8), (0, 1), (0.5, 0.5), 1, "int8_stoch",
+                                interpret=True)
 
 
 # ---------------------------------------------------------------------------

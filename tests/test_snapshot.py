@@ -153,6 +153,27 @@ def test_snapshotter_failure_recorded_never_raised(tmp_path, monkeypatch):
     assert "disk on fire" in sn.stats.last_error
 
 
+def test_launcher_fails_when_a_snapshot_save_failed(tmp_path, monkeypatch):
+    """The writer thread keeps training alive through a failed save, but the
+    launcher's run as a whole must not report success."""
+    def boom(*a, **kw):
+        raise OSError("disk on fire")
+
+    saved = dict(os.environ)  # the launcher wires its env when imported
+    try:
+        from repro.launch import train
+    finally:
+        os.environ.clear()
+        os.environ.update(saved)
+    monkeypatch.setattr(checkpoint, "save", boom)
+    with pytest.raises(RuntimeError, match="disk on fire"):
+        train.main(["--arch", "granite-8b", "--reduced", "--layers", "1",
+                    "--steps", "4", "--batch", "2", "--seq", "16",
+                    "--superstep", "2", "--prefetch", "0",
+                    "--checkpoint", str(tmp_path), "--checkpoint-every", "1",
+                    "--checkpoint-budget", "0"])
+
+
 def test_snapshotter_retention_keeps_last_k(tmp_path):
     d = _stub_driver()
     with RunSnapshotter(str(tmp_path), every=1, keep_last=2,
