@@ -1,0 +1,10 @@
+"""Per training step, the device time of the consensus-error diagnostic:
+the self time of the operations under the `consensus_error` scope of the
+compiled superstep in the traced window, mean over chips (device trace,
+each operation's scope read from the executable that ran;
+`bench/spans.py`)."""
+from bench import spans
+
+
+def read(ctx):
+    return spans.step_ms(ctx, "consensus_error")

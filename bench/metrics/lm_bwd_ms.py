@@ -1,0 +1,10 @@
+"""Per training step, the device time of the backward pass: the self time
+of the compiled superstep's operations under `transpose(jvp(fwd))`, the
+forward recomputed under remat included, in the traced window, mean over
+chips (device trace; each operation's scope is read from the executable that
+ran, `bench/spans.py`)."""
+from bench import spans
+
+
+def read(ctx):
+    return spans.step_ms(ctx, "bwd")

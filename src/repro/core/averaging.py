@@ -226,19 +226,26 @@ def average_and_error(tree: Tree, cfg: AveragingConfig, *, n_nodes: int,
         mix = make_gossip_mix(cfg, pods if cfg.mode == "hierarchical"
                               else n_nodes)
     if not (cfg.packed and _packable(mix)):
-        mixed = average_gradients(tree, cfg, n_nodes=n_nodes, pods=pods,
-                                  mix=mix, key=key, t=t)
+        with jax.named_scope("gossip.mix"):
+            mixed = average_gradients(tree, cfg, n_nodes=n_nodes, pods=pods,
+                                      mix=mix, key=key, t=t)
         return mixed, consensus_error(mixed)
-    bufs, spec = packing.pack_tree(tree)
-    if cfg.mode == "gossip":
-        outs = tuple(_apply_mix(mix, spec, g, b, key, t)
-                     for g, b in enumerate(bufs))
-    else:
-        assert n_nodes % pods == 0
-        outs = tuple(_hmix_buffer(b, pods, n_nodes // pods, mix, key, t)
-                     for b in bufs)
+    # named scopes mark the consensus's parts in the compiled program's
+    # metadata (gossip.pack / gossip.mix / gossip.unpack, consensus_error),
+    # so a device trace can be split by part
+    with jax.named_scope("gossip.pack"):
+        bufs, spec = packing.pack_tree(tree)
+    with jax.named_scope("gossip.mix"):
+        if cfg.mode == "gossip":
+            outs = tuple(_apply_mix(mix, spec, g, b, key, t)
+                         for g, b in enumerate(bufs))
+        else:
+            assert n_nodes % pods == 0
+            outs = tuple(_hmix_buffer(b, pods, n_nodes // pods, mix, key, t)
+                         for b in bufs)
     err = _packed_consensus_error(outs, spec)
-    return packing.unpack_tree(outs, spec), err
+    with jax.named_scope("gossip.unpack"):
+        return packing.unpack_tree(outs, spec), err
 
 
 def ef_average_and_error(tree: Tree, ef: Tree, cfg: AveragingConfig, *,
@@ -271,29 +278,32 @@ def ef_average_and_error(tree: Tree, ef: Tree, cfg: AveragingConfig, *,
             "error feedback needs a LINEAR consensus operator — build it via "
             "make_gossip_mix, which drops the per-round compressor when "
             "cfg.error_feedback is on")
-    bufs, spec = packing.pack_tree(tree)
-    ebufs, espec = packing.pack_tree(ef)
+    with jax.named_scope("gossip.pack"):
+        bufs, spec = packing.pack_tree(tree)
+        ebufs, espec = packing.pack_tree(ef)
     outs, res = [], []
     v2 = jnp.zeros((), jnp.float32)
     e2 = jnp.zeros((), jnp.float32)
-    for g, (b, e) in enumerate(zip(bufs, ebufs)):
-        v = b.astype(jnp.float32) + e.astype(jnp.float32)
-        if cfg.quantization == "none" or b.shape[-1] == 0:
-            q = v
-        else:
-            k = jax.random.fold_in(key, g) if key is not None else None
-            q = tile_compress(v, cfg.quantization, cfg.quant_block_d,
-                              key=k, per_node=True)
-        outs.append(_mix_call(mix, q, key=None, t=t).astype(b.dtype))
-        r = v - q
-        res.append(r.astype(e.dtype))
-        v2 = v2 + jnp.sum(v * v)
-        e2 = e2 + jnp.sum(r.astype(jnp.float32) ** 2)
+    with jax.named_scope("gossip.mix"):
+        for g, (b, e) in enumerate(zip(bufs, ebufs)):
+            v = b.astype(jnp.float32) + e.astype(jnp.float32)
+            if cfg.quantization == "none" or b.shape[-1] == 0:
+                q = v
+            else:
+                k = jax.random.fold_in(key, g) if key is not None else None
+                q = tile_compress(v, cfg.quantization, cfg.quant_block_d,
+                                  key=k, per_node=True)
+            outs.append(_mix_call(mix, q, key=None, t=t).astype(b.dtype))
+            r = v - q
+            res.append(r.astype(e.dtype))
+            v2 = v2 + jnp.sum(v * v)
+            e2 = e2 + jnp.sum(r.astype(jnp.float32) ** 2)
+        ef_norm = jnp.sqrt(e2)
+        ef_rel = ef_norm / (jnp.sqrt(v2) + 1e-30)
     err = _packed_consensus_error(tuple(outs), spec)
-    ef_norm = jnp.sqrt(e2)
-    ef_rel = ef_norm / (jnp.sqrt(v2) + 1e-30)
-    return (packing.unpack_tree(tuple(outs), spec),
-            packing.unpack_tree(tuple(res), espec), err, ef_norm, ef_rel)
+    with jax.named_scope("gossip.unpack"):
+        return (packing.unpack_tree(tuple(outs), spec),
+                packing.unpack_tree(tuple(res), espec), err, ef_norm, ef_rel)
 
 
 def _packed_consensus_error(bufs: Tuple[jax.Array, ...],
@@ -303,18 +313,19 @@ def _packed_consensus_error(bufs: Tuple[jax.Array, ...],
     leaf segment by `packing.segment_sums` (static contiguous slices — exact,
     scatter-free, sharding-friendly), so a hundred-leaf tree stops paying a
     hundred independent norm chains."""
-    errs = []
-    for g, buf in enumerate(bufs):
-        if buf.shape[-1] == 0:
-            continue
-        widths = [spec.leaf_width(i) for i in spec.groups[g]]
-        b = buf.astype(jnp.float32)
-        bar = jnp.mean(b, axis=0, keepdims=True)
-        d2 = packing.segment_sums((b - bar) ** 2, widths)  # [N, S]
-        num = jnp.max(jnp.sqrt(d2), axis=0)  # [S]
-        den = jnp.sqrt(packing.segment_sums(bar[0] ** 2, widths)) + 1e-30
-        errs.append(jnp.max(num / den))
-    return jnp.max(jnp.stack(errs)) if errs else jnp.zeros(())
+    with jax.named_scope("consensus_error"):
+        errs = []
+        for g, buf in enumerate(bufs):
+            if buf.shape[-1] == 0:
+                continue
+            widths = [spec.leaf_width(i) for i in spec.groups[g]]
+            b = buf.astype(jnp.float32)
+            bar = jnp.mean(b, axis=0, keepdims=True)
+            d2 = packing.segment_sums((b - bar) ** 2, widths)  # [N, S]
+            num = jnp.max(jnp.sqrt(d2), axis=0)  # [S]
+            den = jnp.sqrt(packing.segment_sums(bar[0] ** 2, widths)) + 1e-30
+            errs.append(jnp.max(num / den))
+        return jnp.max(jnp.stack(errs)) if errs else jnp.zeros(())
 
 
 def consensus_error(tree: Tree) -> jax.Array:
@@ -322,8 +333,9 @@ def consensus_error(tree: Tree) -> jax.Array:
     epsilon-accuracy diagnostic for inexact averaging. Computed on the packed
     flat buffer (single fused reduction; `consensus_error_per_leaf` is the
     per-leaf oracle)."""
-    bufs, spec = packing.pack_tree(tree)
-    return _packed_consensus_error(bufs, spec)
+    with jax.named_scope("consensus_error"):
+        bufs, spec = packing.pack_tree(tree)
+        return _packed_consensus_error(bufs, spec)
 
 
 def consensus_error_per_leaf(tree: Tree) -> jax.Array:

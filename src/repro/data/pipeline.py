@@ -17,7 +17,8 @@ Two streaming-engine extensions (see train.driver for the full picture):
   synthesis + H2D with device work (the compute/stream overlap of Fig. 4).
   Each staged item carries a counter snapshot so consumer-visible accounting
   (`samples_arrived`, `samples_discarded`, `rounds`) stays coherent with the
-  batch being trained on, not with how far ahead the producer has run.
+  batch being trained on, not with how far ahead the producer has run, and
+  the host seconds its producer spent on it (`repro.obs` spans).
 * **Checkpoint continuity** — the splitter's exact stream position
   (counter quad + PRNG bit-generator state + live plan) is exported by
   `splitter_state()` / restored by `load_splitter_state()` (both from
@@ -40,10 +41,12 @@ from __future__ import annotations
 
 import queue
 import threading
-from typing import Any, Callable, Dict, Iterator, NamedTuple, Optional
+import time
+from typing import Any, Callable, Dict, Iterator, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.configs.base import StreamConfig
 from repro.core.rates import BucketLadder, Plan, plan as make_plan
 from repro.core.streaming import GovernedPlanMixin
@@ -100,14 +103,21 @@ class StreamingPipeline(GovernedPlanMixin):
     def __next__(self) -> Dict[str, np.ndarray]:
         return self._round(self._latch_plan())
 
-    def next_superstep(self, k: int) -> Dict[str, np.ndarray]:
+    def next_superstep(self, k: int, sink: Optional[Dict[str, float]] = None,
+                       **ids) -> Dict[str, np.ndarray]:
         """Draw K governed rounds and stack them: leaves [K, B, ...]. The
         plan is latched once for the whole superstep, so a concurrent
         `update_plan` can never produce ragged round widths within one
-        stack."""
+        stack. Each round is a `stream.draw` span and the stack a
+        `stream.stack` span (`repro.obs`), timed into `sink` and carrying
+        `ids`."""
         plan = self._latch_plan()
-        rounds = [self._round(plan) for _ in range(k)]
-        out = {key: np.stack([r[key] for r in rounds]) for key in rounds[0]}
+        rounds = []
+        for _ in range(k):
+            with obs.span("stream.draw", sink, **ids):
+                rounds.append(self._round(plan))
+        with obs.span("stream.stack", sink, **ids):
+            out = {key: np.stack([r[key] for r in rounds]) for key in rounds[0]}
         self._last_superstep_plan = plan
         return out
 
@@ -118,6 +128,26 @@ class _Stop:
 
 class _Raise(NamedTuple):
     exc: BaseException
+
+
+class _Staged(NamedTuple):
+    batch: Any
+    counters: Optional[StreamCounters]
+    meta: Any
+    host_s: Optional[Dict[str, float]]
+    ready: float  # perf_counter when the producer began to offer it
+
+
+class _Ring(queue.Queue):
+    """The bounded FIFO between producer and consumer. As a staged item
+    enters (under the ring's lock, before the consumer can take it), its
+    `host_s` gains `ring_put_wait`: the seconds its producer waited for
+    room, i.e. back-pressure from the consumer."""
+
+    def _put(self, item) -> None:
+        if isinstance(item, _Staged) and item.host_s is not None:
+            item.host_s["ring_put_wait"] = time.perf_counter() - item.ready
+        super()._put(item)
 
 
 class DevicePrefetcher:
@@ -135,12 +165,22 @@ class DevicePrefetcher:
     pipeline's `last_superstep_plan`, so the consumer knows which batch
     bucket a staged superstep was dealt at even while the ring drains items
     produced under a superseded plan).
+
+    The optional `spans` hook, sampled with them, returns the host-seconds
+    dict of the superstep just produced and the ids its spans carry
+    (`repro.obs`): the wait for room in the ring is a
+    `stream.ring_put_wait` span with those ids, and its seconds enter that
+    dict as the item enters the ring. `__next__` exposes the dict as
+    `host_s`, and `ring_depth` is the number of staged items left behind
+    the one taken.
     """
 
     def __init__(self, produce: Callable[[], Any], *,
                  stage: Optional[Callable[[Any], Any]] = None,
                  counters: Optional[Callable[[], StreamCounters]] = None,
                  meta: Optional[Callable[[], Any]] = None,
+                 spans: Optional[Callable[[], Tuple[Optional[Dict[str, float]],
+                                                    Dict[str, Any]]]] = None,
                  depth: int = 2):
         if depth < 1:
             raise ValueError("prefetch depth must be >= 1")
@@ -148,7 +188,8 @@ class DevicePrefetcher:
         self._stage = stage or (lambda x: x)
         self._counters = counters or (lambda: None)
         self._meta = meta or (lambda: None)
-        self._q: queue.Queue = queue.Queue(maxsize=depth)
+        self._spans = spans or (lambda: (None, {}))
+        self._q: queue.Queue = _Ring(maxsize=depth)
         self._stop = threading.Event()
         self._final: Optional[Any] = None  # latched _Stop/_Raise terminal state
         self._undelivered: Optional[_Raise] = None  # error stranded by close()
@@ -156,6 +197,8 @@ class DevicePrefetcher:
         self._error_delivered = False  # __next__ already surfaced the error
         self.counters: Optional[StreamCounters] = None
         self.meta: Optional[Any] = None
+        self.host_s: Optional[Dict[str, float]] = None
+        self.ring_depth = 0
         self._thread = threading.Thread(target=self._worker, daemon=True,
                                         name="device-prefetch")
         self._thread.start()
@@ -183,8 +226,11 @@ class DevicePrefetcher:
                     break
                 snap = self._counters()
                 meta = self._meta()
+                host_s, ids = self._spans()
                 staged = self._stage(item)
-                self._put_stopaware((staged, snap, meta))
+                with obs.span("stream.ring_put_wait", **ids):
+                    self._put_stopaware(_Staged(staged, snap, meta, host_s,
+                                                time.perf_counter()))
         except BaseException as e:  # surface producer failures at the consumer
             if not self._put_stopaware(_Raise(e)):
                 self._undelivered = _Raise(e)
@@ -205,12 +251,13 @@ class DevicePrefetcher:
             self._final = got
             self._error_delivered = True
             raise got.exc
-        staged, snap, meta = got
-        if snap is not None:
-            self.counters = snap
-        if meta is not None:
-            self.meta = meta
-        return staged
+        self.ring_depth = self._q.qsize()
+        if got.counters is not None:
+            self.counters = got.counters
+        if got.meta is not None:
+            self.meta = got.meta
+        self.host_s = got.host_s
+        return got.batch
 
     def _drain(self) -> Optional[_Raise]:
         """Empty the ring; return the last pending `_Raise` found, if any."""

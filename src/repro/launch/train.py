@@ -290,12 +290,18 @@ def _log(rec):
     if "est_Rc" in rec:
         rc = rec["est_Rc"]
         gov += f" est_Rc={'inf' if rc <= 0 else f'{rc:.3g}'}"
+    # host seconds of the superstep (docs/DESIGN.md §Observability): a ring
+    # wait near the superstep's wall time means the splitter sets the pace
+    host = rec["host_s"]
+    splitter = sum(host.get(k, 0.0) for k in ("draw", "stack", "node_split"))
     print(f"round {rec['round']:5d} loss {m['loss']:.4f} ce {m['ce']:.4f} "
           f"consensus_err {m['consensus_err']:.2e} "
           f"t'={c.samples_arrived} B={rec['bucket']} mu={plan.mu} "
           f"{plan.regime}{gov} "
           f"({rec['rounds_per_s']:.1f} rounds/s, "
-          f"{rec['samples_per_s']:.0f} samples/s)", flush=True)
+          f"{rec['samples_per_s']:.0f} samples/s, "
+          f"ring wait {1e3 * host['ring_wait']:.1f} ms, "
+          f"splitter {1e3 * splitter:.1f} ms)", flush=True)
 
 
 def _draw(data: MarkovTokenStream, rng: np.random.Generator, n: int, seq: int):

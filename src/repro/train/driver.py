@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import GovernorConfig, RunConfig
 from repro.core import dsgd, rates
 from repro.core.faults import FaultSchedule
@@ -236,6 +237,10 @@ class StreamingDriver:
         self._sharding = self._batch_sharding()
         self._prefetcher: Optional[DevicePrefetcher] = None
         self._supersteps_done = 0  # across run() calls
+        # producer side: the next superstep's sequence number, and the
+        # host-seconds dict and span ids of the one being dealt
+        self._dealt = 0
+        self._dealing: Tuple[Optional[Dict[str, float]], Dict[str, int]] = (None, {})
         # governor warm-up gate, per jit signature: supersteps completed at
         # each (bucket, cohort) signature (the first of a fresh signature
         # pays XLA compile time and must not feed replan or the estimator)
@@ -352,12 +357,21 @@ class StreamingDriver:
     def _host_superstep(self) -> Dict[str, np.ndarray]:
         """Stage 1: K governed splitter rounds, stacked [K, B, ...] (exact)
         or split [K, N, B/N, ...] over the *active cohort* (decentralized
-        node axis; the latched plan's membership decides the split)."""
-        batch = self.pipeline.next_superstep(self.engine.superstep)
+        node axis; the latched plan's membership decides the split).
+
+        Runs on the producer thread (the consumer's when prefetch depth is
+        0) and opens `_dealing`, the host-seconds dict of the superstep it
+        deals and the `step` its spans carry; `_stage` and the prefetch
+        ring add to the same dict."""
+        sink, ids = {}, {"step": self._dealt}
+        self._dealt += 1
+        self._dealing = (sink, ids)
+        batch = self.pipeline.next_superstep(self.engine.superstep, sink, **ids)
         if self.decentralized:
             p = self.pipeline.last_superstep_plan
             m = self.n_nodes if p.membership is None else p.membership.n_active
-            batch = make_node_batch(batch, m, axis=1)
+            with obs.span("stream.node_split", sink, **ids):
+                batch = make_node_batch(batch, m, axis=1)
         return batch
 
     def _batch_sharding(self) -> Optional[NamedSharding]:
@@ -379,9 +393,11 @@ class StreamingDriver:
 
     def _stage(self, batch: Dict[str, np.ndarray]):
         """Stage 2: H2D — runs on the prefetch thread when depth > 0."""
-        if self._sharding is None:
-            return jax.device_put(batch)
-        return jax.device_put(batch, self._sharding)
+        sink, ids = self._dealing
+        with obs.span("stream.stage", sink, **ids):
+            if self._sharding is None:
+                return jax.device_put(batch)
+            return jax.device_put(batch, self._sharding)
 
     # ------------------------------------------------------------- main loop
 
@@ -390,13 +406,21 @@ class StreamingDriver:
             log_every: int = 1) -> Tuple[TrainState, List[Dict[str, Any]]]:
         """Drive `supersteps` supersteps (K rounds each). Returns the final
         TrainState and the per-superstep history of metrics, throughput, and
-        governor decisions.
+        governor decisions. Each record also holds `host_s`, the host
+        seconds of each `stream.*` span of that superstep (producer and
+        consumer; `repro.obs`), and `ring_depth`, the staged supersteps left
+        in the prefetch ring when the consumer took this one
+        (docs/DESIGN.md §Observability).
 
         The prefetch ring persists across calls (it keeps staging between
         runs, bounded at `prefetch_depth`), so a warm-up `run()` leaves the
         ring hot for a subsequent timed one; call `close()` (or use the
         driver as a context manager) when done."""
         if self.engine.prefetch_depth > 0 and self._prefetcher is None:
+            # the ring is FIFO: the producer numbers supersteps from the
+            # next one the consumer will take, so both sides' spans of a
+            # superstep carry the same `step`
+            self._dealt = self._supersteps_done
             self._prefetcher = DevicePrefetcher(
                 self._host_superstep, stage=self._stage,
                 counters=self.pipeline.counters,
@@ -405,30 +429,49 @@ class StreamingDriver:
                 # the consumer-side checkpoint pins exactly what it consumed
                 meta=lambda: (self.pipeline.last_superstep_plan,
                               self.pipeline.splitter_state()),
+                spans=lambda: self._dealing,
                 depth=self.engine.prefetch_depth)
-        source = self._prefetcher
         for i in range(supersteps):
-            # membership changes land OUTSIDE the timed window: the swap (and
-            # any rejoin state sync) is engine bookkeeping, not stream
-            # processing the governor should bill to R_p
-            if self._elastic:
-                self._apply_membership(self._supersteps_done)
-            # the timed window covers batch acquisition too: when the HOST is
-            # the bottleneck (prefetch ring empty, slow synthesis), that wait
-            # must show up in measured_Re or the governor would keep calling
-            # an input-bound run "resourceful"
-            t0 = self.clock()
+            with jax.profiler.StepTraceAnnotation(
+                    "stream.superstep", step_num=self._supersteps_done):
+                rec = self._superstep()
+            if log_fn and (i % log_every == 0 or i == supersteps - 1):
+                log_fn(rec)
+        return self.state, self.history
+
+    def _superstep(self) -> Dict[str, Any]:
+        """One superstep on the consumer side: take the next staged batch,
+        run the compiled superstep, fetch its metrics, feed the governor,
+        then publish and snapshot. Returns its history record."""
+        source = self._prefetcher
+        step = self._supersteps_done
+        host_s: Dict[str, float] = {}
+        # membership changes land OUTSIDE the timed window: the swap (and
+        # any rejoin state sync) is engine bookkeeping, not stream
+        # processing the governor should bill to R_p
+        if self._elastic:
+            self._apply_membership(step)
+        # the timed window covers batch acquisition too: when the HOST is
+        # the bottleneck (prefetch ring empty, slow synthesis), that wait
+        # must show up in the round time the governor inverts, or it would
+        # keep calling an input-bound run "resourceful"
+        t0 = self.clock()
+        with obs.span("stream.ring_wait", host_s, step=step):
             if source is not None:
                 staged = next(source)
                 counters = source.counters
                 used_plan, split_state = source.meta or (None, None)
+                dealt_s, ring_depth = source.host_s, source.ring_depth
             else:
+                self._dealt = step
                 staged = self._stage(self._host_superstep())
                 counters = self.pipeline.counters()
                 used_plan = self.pipeline.last_superstep_plan
                 split_state = self.pipeline.splitter_state()
-            if split_state is not None:
-                self._last_splitter_state = split_state
+                dealt_s, ring_depth = self._dealing[0], 0
+        if split_state is not None:
+            self._last_splitter_state = split_state
+        with obs.span("stream.dispatch", host_s, step=step):
             # after a bucket or membership switch the ring may still drain
             # supersteps dealt at the old width/cohort: each batch runs
             # through the compiled executable of the (bucket, cohort) that
@@ -442,25 +485,28 @@ class StreamingDriver:
                                          staged)
             else:
                 self.state, metrics = fn(self.state, staged)
+        with obs.span("stream.fetch", host_s, step=step):
             metrics = jax.device_get(metrics)  # one fetch per K rounds
-            wall_s = max(self.clock() - t0, 1e-12)
+        wall_s = max(self.clock() - t0, 1e-12)
+        # the producer's seconds for this superstep, then the consumer's
+        host_s = {**(dealt_s or {}), **host_s}
+        with obs.span("stream.observe", host_s, step=step):
             rec = self._observe(metrics, wall_s, counters, used_plan)
-            if self._publisher is not None:
-                # outside the timed window, at the plan-latch barrier: the
-                # publisher's copy dispatch is async and its own governor
-                # keeps the cost within the configured overhead budget
-                snap = self._publisher.maybe_publish(
-                    self.state, self._supersteps_done, aux=self._publish_aux())
-                rec["published_version"] = snap.version if snap else None
-            if self._snapshotter is not None:
-                # superstep boundary, after publication: the copy dispatch is
-                # async and the writer thread owns all disk I/O — the
-                # snapshotter's cost governor bounds what lands here
-                ck = self._snapshotter.maybe_snapshot(self)
-                rec["checkpoint"] = ck["step"] if ck else None
-            if log_fn and (i % log_every == 0 or i == supersteps - 1):
-                log_fn(rec)
-        return self.state, self.history
+        rec["host_s"], rec["ring_depth"] = host_s, ring_depth
+        if self._publisher is not None:
+            # outside the timed window, at the plan-latch barrier: the
+            # publisher's copy dispatch is async and its own governor
+            # keeps the cost within the configured overhead budget
+            snap = self._publisher.maybe_publish(
+                self.state, self._supersteps_done, aux=self._publish_aux())
+            rec["published_version"] = snap.version if snap else None
+        if self._snapshotter is not None:
+            # superstep boundary, after publication: the copy dispatch is
+            # async and the writer thread owns all disk I/O — the
+            # snapshotter's cost governor bounds what lands here
+            ck = self._snapshotter.maybe_snapshot(self)
+            rec["checkpoint"] = ck["step"] if ck else None
+        return rec
 
     def _publish_aux(self) -> Optional[jax.Array]:
         """The publisher extract's aux: a [N] float membership mask for
@@ -580,8 +626,6 @@ class StreamingDriver:
         warm = seen >= (self.engine.warmup_supersteps
                         if sig == self._initial_sig
                         else self.engine.warmup_per_bucket)
-        measured_Rp = rates.measured_processing_rate(
-            B_used, m_used, used_plan.R, round_s, stream.comms_rate)
         rec: Dict[str, Any] = {
             "superstep": i,
             "round": (i + 1) * K,
@@ -590,8 +634,6 @@ class StreamingDriver:
             "wall_s": wall_s,
             "rounds_per_s": K / wall_s,
             "samples_per_s": K * B_used / wall_s,
-            "measured_Rp": measured_Rp,
-            "measured_Re": rates.measured_effective_rate(round_s),
             "plan": used_plan,
             "bucket": B_used,
             "n_active": m_used,

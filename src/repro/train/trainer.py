@@ -142,8 +142,13 @@ def build_train_step(run: RunConfig, mesh, *,
         raise ValueError("error_feedback requires averaging mode 'gossip' "
                          f"(got {run.averaging.mode!r})")
 
+    # named scopes (`fwd`, `optimizer`; the consensus's own in
+    # core.averaging) mark the step's parts in the compiled program's
+    # metadata, so a device trace can be split by part; autodiff names the
+    # backward pass `transpose(jvp(fwd))`
     def loss(params, batch):
-        return registry.loss_fn(params, cfg, batch, remat=run.remat)
+        with jax.named_scope("fwd"):
+            return registry.loss_fn(params, cfg, batch, remat=run.remat)
 
     if not decentralized:
         def grad_of(params, batch):
@@ -187,7 +192,8 @@ def build_train_step(run: RunConfig, mesh, *,
                     acc_fn, (zero_g, jnp.zeros(()), zero_m), mbatch)
             else:
                 (l, metrics), grads = grad_of(state.params, batch)
-            new_params, new_opt = update(grads, state.opt, state.params)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = update(grads, state.opt, state.params)
             metrics = dict(metrics, loss=l, consensus_err=jnp.zeros(()))
             return TrainState(new_params, new_opt), metrics
         return train_step, partial(_state_specs, run=run, mesh=mesh, node_axes=None)
@@ -239,7 +245,8 @@ def build_train_step(run: RunConfig, mesh, *,
             mixed, cerr = average_and_error(grads, run.averaging,
                                             n_nodes=n_nodes, pods=pods,
                                             mix=mix, key=step_key, t=t)
-        new_params, new_opt = jax.vmap(update)(mixed, state.opt, state.params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = jax.vmap(update)(mixed, state.opt, state.params)
         metrics = jax.tree.map(jnp.mean, metrics)
         metrics = dict(metrics, loss=jnp.mean(l), consensus_err=cerr)
         if ef_on:
