@@ -30,7 +30,8 @@ packed buffer in one pass, "tile" takes the fused quantized kernel.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+from functools import partial
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -213,13 +214,19 @@ def average_gradients(tree: Tree, cfg: AveragingConfig, *, n_nodes: int,
 
 def average_and_error(tree: Tree, cfg: AveragingConfig, *, n_nodes: int,
                       pods: int = 1, mix: Optional[MixOp] = None,
-                      key: Any = None, t: Any = None) -> Tuple[Tree, jax.Array]:
+                      key: Any = None, t: Any = None,
+                      diag: Any = True) -> Tuple[Tree, jax.Array]:
     """Averaging plus the epsilon-consensus diagnostic with ONE pack: the
     mixed packed buffers feed both the unpack and the fused error reduction,
-    so the trainer stops paying a second per-leaf (or re-pack) sweep."""
+    so the trainer stops paying a second per-leaf (or re-pack) sweep.
+
+    `diag` says whether to compute the diagnostic (`_diagnostic`): a plain
+    step leaves it True; the K-round superstep scan passes a traced flag
+    true in its last round only, so its stacked `consensus_err` holds the
+    value in the last round and NaN in the others."""
     if cfg.mode == "exact":
         mixed = exact_average(tree)
-        return mixed, consensus_error(mixed)
+        return mixed, _diagnostic(consensus_error, mixed, diag)
     if cfg.mode not in ("gossip", "hierarchical"):
         raise ValueError(f"unknown averaging mode {cfg.mode!r}")
     if mix is None:
@@ -229,7 +236,7 @@ def average_and_error(tree: Tree, cfg: AveragingConfig, *, n_nodes: int,
         with jax.named_scope("gossip.mix"):
             mixed = average_gradients(tree, cfg, n_nodes=n_nodes, pods=pods,
                                       mix=mix, key=key, t=t)
-        return mixed, consensus_error(mixed)
+        return mixed, _diagnostic(consensus_error, mixed, diag)
     # named scopes mark the consensus's parts in the compiled program's
     # metadata (gossip.pack / gossip.mix / gossip.unpack, consensus_error),
     # so a device trace can be split by part
@@ -243,14 +250,14 @@ def average_and_error(tree: Tree, cfg: AveragingConfig, *, n_nodes: int,
             assert n_nodes % pods == 0
             outs = tuple(_hmix_buffer(b, pods, n_nodes // pods, mix, key, t)
                          for b in bufs)
-    err = _packed_consensus_error(outs, spec)
+    err = _diagnostic(partial(_packed_consensus_error, spec=spec), outs, diag)
     with jax.named_scope("gossip.unpack"):
         return packing.unpack_tree(outs, spec), err
 
 
 def ef_average_and_error(tree: Tree, ef: Tree, cfg: AveragingConfig, *,
                          n_nodes: int, mix: Optional[MixOp] = None,
-                         key: Any = None, t: Any = None
+                         key: Any = None, t: Any = None, diag: Any = True
                          ) -> Tuple[Tree, Tree, jax.Array, jax.Array, jax.Array]:
     """Error-feedback compressed gossip: ONE pack, ONE compression, exact
     linear consensus rounds (docs/DESIGN.md §Decentralized LM track).
@@ -270,6 +277,8 @@ def ef_average_and_error(tree: Tree, ef: Tree, cfg: AveragingConfig, *,
 
     Returns (mixed, new_ef, consensus_err, ef_norm, ef_rel): `ef_norm` is
     the global L2 norm of the new residual, `ef_rel` its ratio to ||v||.
+    `consensus_err` follows `diag` as in `average_and_error`: in a superstep
+    scan it holds the value in the last round only, and NaN in the others.
     """
     if mix is None:
         mix = make_gossip_mix(cfg, n_nodes)
@@ -300,10 +309,28 @@ def ef_average_and_error(tree: Tree, ef: Tree, cfg: AveragingConfig, *,
             e2 = e2 + jnp.sum(r.astype(jnp.float32) ** 2)
         ef_norm = jnp.sqrt(e2)
         ef_rel = ef_norm / (jnp.sqrt(v2) + 1e-30)
-    err = _packed_consensus_error(tuple(outs), spec)
+    err = _diagnostic(partial(_packed_consensus_error, spec=spec),
+                      tuple(outs), diag)
     with jax.named_scope("gossip.unpack"):
         return (packing.unpack_tree(tuple(outs), spec),
                 packing.unpack_tree(tuple(res), espec), err, ef_norm, ef_rel)
+
+
+def _diagnostic(err_fn: Callable[[Any], jax.Array], operand: Any,
+                diag: Any) -> jax.Array:
+    """`err_fn(operand)`, the consensus diagnostic, where `diag` asks for it.
+
+    `diag` True, a Python constant (a plain step), computes it with no
+    `cond`. A traced flag (the superstep scan's round position) runs it
+    under `lax.cond`, built inside the `consensus_error` scope so the
+    branch's ops keep that scope in the compiled program; rounds that skip
+    it read NaN, not 0, which would read as perfect consensus."""
+    if diag is True:
+        return err_fn(operand)
+    with jax.named_scope("consensus_error"):
+        return jax.lax.cond(diag, err_fn,
+                            lambda _: jnp.full((), jnp.nan, jnp.float32),
+                            operand)
 
 
 def _packed_consensus_error(bufs: Tuple[jax.Array, ...],

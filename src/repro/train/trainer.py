@@ -114,6 +114,9 @@ def build_train_step(run: RunConfig, mesh, *,
     """Returns (train_step, state_spec_fn).
 
     train_step(state, batch) -> (state, metrics); call under `mesh_rules`.
+    The decentralized step takes `diag=True`, whether to compute the
+    consensus diagnostic (`core.averaging.average_and_error`);
+    `build_superstep` passes a traced flag true in the scan's last round.
 
     `n_nodes` overrides the mesh-derived decentralized node count: passing
     N > n_data_nodes(mesh) emulates the paper's N-node network on fewer
@@ -210,7 +213,7 @@ def build_train_step(run: RunConfig, mesh, *,
         raise ValueError("ScheduledMixOp is linear-only: quantized averaging "
                          "configs keep their static per-round operator")
 
-    def train_step(state: TrainState, batch):
+    def train_step(state: TrainState, batch, diag: Any = True):
         # batch leaves: [n_nodes, B/n_nodes, ...]
         def node_loss_grad(params, node_batch):
             return jax.value_and_grad(loss, has_aux=True)(params, node_batch)
@@ -240,11 +243,12 @@ def build_train_step(run: RunConfig, mesh, *,
             # residual in OptState.ef_residual (core.averaging docstring)
             mixed, new_ef, cerr, ef_norm, ef_rel = ef_average_and_error(
                 grads, state.opt.ef_residual, run.averaging,
-                n_nodes=n_nodes, mix=mix, key=step_key, t=t)
+                n_nodes=n_nodes, mix=mix, key=step_key, t=t, diag=diag)
         else:
             mixed, cerr = average_and_error(grads, run.averaging,
                                             n_nodes=n_nodes, pods=pods,
-                                            mix=mix, key=step_key, t=t)
+                                            mix=mix, key=step_key, t=t,
+                                            diag=diag)
         with jax.named_scope("optimizer"):
             new_params, new_opt = jax.vmap(update)(mixed, state.opt, state.params)
         metrics = jax.tree.map(jnp.mean, metrics)
@@ -300,11 +304,26 @@ def build_superstep(run: RunConfig, mesh, *,
     pays one dispatch and one metric fetch per K rounds instead of per round.
     K is read from the batch shapes at trace time; each distinct K compiles
     once (jit caches by shape), so pick K once per run.
+
+    Decentralized modes compute the consensus diagnostic in the last round
+    only: each round's position comes in as scan data beside its batch, and
+    the stacked `consensus_err` holds the value in the last round and NaN in
+    the others (the driver's record keeps the last round). Exact mode runs no
+    diagnostic and scans its batches alone.
     """
     train_step, spec_fn = build_train_step(run, mesh, n_nodes=n_nodes, mix=mix)
 
+    decentralized = run.averaging.mode != "exact"
+
+    def round_(state: TrainState, xs):
+        batch, last = xs
+        return train_step(state, batch, diag=last)
+
     def superstep(state: TrainState, batches):
-        return jax.lax.scan(train_step, state, batches)
+        if not decentralized:
+            return jax.lax.scan(train_step, state, batches)
+        K = jax.tree.leaves(batches)[0].shape[0]
+        return jax.lax.scan(round_, state, (batches, jnp.arange(K) == K - 1))
 
     return superstep, spec_fn
 

@@ -2,6 +2,7 @@
 
 * superstep(K) == K sequential train steps — bit-identical in exact mode,
   within tolerance for decentralized (gossip) mode
+* the superstep computes the consensus diagnostic in its last round only
 * the async prefetch ring preserves sample order and keeps the splitter
   counters (samples_arrived, discards) coherent with the consumed batch
 * the closed-loop governor raises mu when the measured rate is artificially
@@ -112,6 +113,114 @@ def test_superstep_decentralized_matches_sequential():
     for a, b in zip(jax.tree.leaves(seq_state), jax.tree.leaves(sup_state)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
+
+
+AVERAGING = {
+    "gossip": AveragingConfig("gossip", 2),
+    "ef_gossip": AveragingConfig("gossip", 2, quantization="int8",
+                                 error_feedback="grads"),
+}
+
+
+@pytest.mark.parametrize("mode", sorted(AVERAGING))
+def test_superstep_consensus_err_in_last_round_only(mode):
+    """The superstep computes the consensus diagnostic in its last round only:
+    that round's value is the one K plain steps report on their K-th step,
+    and the skipped rounds read NaN (a 0 would read as perfect consensus)."""
+    run_cfg = dataclasses.replace(_run_cfg(), averaging=AVERAGING[mode])
+    mesh = make_mesh((1, 1), ("data", "model"))
+    n_nodes, K = 4, 3
+    batches = [make_node_batch(b, n_nodes) for b in _rounds(K, batch=4 * BATCH)]
+    with mesh_rules(mesh, activation_rules(mesh, run_cfg.shape, node_axis=True)):
+        state0 = replicate_for_nodes(
+            init_state(run_cfg, jax.random.PRNGKey(0)), n_nodes)
+        step = jax.jit(build_train_step(run_cfg, mesh, n_nodes=n_nodes)[0])
+        superstep = jax.jit(build_superstep(run_cfg, mesh, n_nodes=n_nodes)[0])
+        seq_state, seq_errs = state0, []
+        for b in batches:
+            seq_state, m = step(seq_state, b)
+            seq_errs.append(float(m["consensus_err"]))
+        _, ms = superstep(state0, _stack(batches))
+
+    assert all(np.isfinite(seq_errs)) and seq_errs[-1] > 0.0
+    errs = np.asarray(ms["consensus_err"])
+    assert errs.shape == (K,)
+    assert np.isnan(errs[:-1]).all()
+    np.testing.assert_allclose(errs[-1], seq_errs[-1], rtol=1e-5, atol=1e-6)
+
+
+def test_driver_record_consensus_err_is_the_last_rounds():
+    """The driver's history record keeps the superstep's last round, where
+    the diagnostic ran: finite and positive under inexact averaging."""
+    run_cfg = _run_cfg("gossip", rounds=2)
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with mesh_rules(mesh, activation_rules(mesh, run_cfg.shape, node_axis=True)):
+        state = replicate_for_nodes(init_state(run_cfg, jax.random.PRNGKey(0)), 4)
+        driver = StreamingDriver(
+            run_cfg, mesh, state, _sample_fn(), batch=4 * BATCH, n_nodes=4,
+            engine=EngineConfig(superstep=3, prefetch_depth=0,
+                                warmup_supersteps=0))
+        _, history = driver.run(2)
+    errs = [rec["metrics"]["consensus_err"] for rec in history]
+    assert all(np.isfinite(errs)) and all(e > 0.0 for e in errs)
+
+
+def _walk(jaxpr, in_scope=False, in_cond=False):
+    """Yield (eqn, under the `consensus_error` scope, inside a cond branch)
+    for every equation of a jaxpr and of the jaxprs nested in it (a nested
+    jaxpr's name stack is relative to its equation's)."""
+    for eqn in jaxpr.eqns:
+        scoped = in_scope or "consensus_error" in str(eqn.source_info.name_stack)
+        yield eqn, scoped, in_cond
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub, scoped, in_cond or eqn.primitive.name == "cond")
+
+
+def _superstep_jaxpr(run_cfg, n_nodes=None, K=3):
+    mesh = make_mesh((1, 1), ("data", "model"))
+    batches = _stack(_rounds(K, batch=4 * BATCH))
+    if n_nodes:
+        batches = make_node_batch(batches, n_nodes, axis=1)
+    rules = activation_rules(mesh, run_cfg.shape, node_axis=bool(n_nodes))
+    with mesh_rules(mesh, rules):
+        state = init_state(run_cfg, jax.random.PRNGKey(0))
+        if n_nodes:
+            state = replicate_for_nodes(state, n_nodes)
+        superstep = build_superstep(run_cfg, mesh, n_nodes=n_nodes)[0]
+        return jax.make_jaxpr(superstep)(state, batches).jaxpr, batches
+
+
+@pytest.mark.parametrize("mode", sorted(AVERAGING))
+def test_superstep_diagnostic_sits_in_a_cond_branch(mode):
+    """In the decentralized superstep's scan body, the consensus diagnostic's
+    work lies inside a `cond` branch: outside one, the `consensus_error`
+    scope holds only the `cond` and its scalar predicate, so no round but the
+    last pays for the diagnostic."""
+    run_cfg = dataclasses.replace(_run_cfg(), averaging=AVERAGING[mode])
+    jaxpr, _ = _superstep_jaxpr(run_cfg, n_nodes=4)
+    scan, = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    eqns = list(_walk(scan.params["jaxpr"].jaxpr))
+    outside = [e for e, scoped, in_cond in eqns
+               if scoped and not in_cond and e.primitive.name != "cond"]
+    assert all(v.aval.size == 1 for e in outside
+               for v in (*e.invars, *e.outvars)), outside
+    conds = [e for e, scoped, in_cond in eqns
+             if scoped and not in_cond and e.primitive.name == "cond"]
+    assert len(conds) == 1
+    reduces = [e for e, scoped, in_cond in eqns
+               if scoped and in_cond and e.primitive.name == "reduce_sum"]
+    assert reduces  # the diagnostic itself is in the branch
+
+
+def test_exact_superstep_jaxpr_unchanged_by_the_round_flag():
+    """Exact mode runs no diagnostic: its superstep scans the batches alone,
+    with no extra scan input and no `cond`."""
+    jaxpr, batches = _superstep_jaxpr(_run_cfg("exact"))
+    scan, = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    p = scan.params
+    assert len(scan.invars) - p["num_consts"] - p["num_carry"] == len(
+        jax.tree.leaves(batches))
+    assert not [e for e, _, _ in _walk(jaxpr) if e.primitive.name == "cond"]
 
 
 # ---------------------------------------------------------------------------
