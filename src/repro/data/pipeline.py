@@ -11,6 +11,8 @@ Two streaming-engine extensions (see train.driver for the full picture):
 * **Supersteps** — `next_superstep(K)` draws K governed rounds and stacks them
   on a new leading K axis, feeding the K-round `lax.scan` inside the jitted
   train step so dispatch and metric-fetch overhead is paid once per K rounds.
+  Given `out`, it stacks into the caller's buffers instead of fresh arrays
+  (the driver recycles a few host buffer sets: train.driver).
 * **Async prefetch** — `DevicePrefetcher` runs the governed splitter in a
   background thread and stages the *next* superstep onto devices
   (`jax.device_put`) while the current one computes, overlapping host sample
@@ -104,20 +106,35 @@ class StreamingPipeline(GovernedPlanMixin):
         return self._round(self._latch_plan())
 
     def next_superstep(self, k: int, sink: Optional[Dict[str, float]] = None,
+                       *, out: Optional[Any] = None,
                        **ids) -> Dict[str, np.ndarray]:
         """Draw K governed rounds and stack them: leaves [K, B, ...]. The
         plan is latched once for the whole superstep, so a concurrent
         `update_plan` can never produce ragged round widths within one
         stack. Each round is a `stream.draw` span and the stack a
         `stream.stack` span (`repro.obs`), timed into `sink` and carrying
-        `ids`."""
+        `ids`.
+
+        Without `out` every call stacks into fresh arrays. With `out` the
+        rounds are stacked into the arrays it gives, which are returned:
+        `out` is a dict of arrays keyed like the rounds, or a function
+        that takes the superstep's layout, `((key, shape, dtype), ...)`,
+        and returns one (so a caller can choose buffers by a shape it
+        learns only from the draws)."""
         plan = self._latch_plan()
         rounds = []
         for _ in range(k):
             with obs.span("stream.draw", sink, **ids):
                 rounds.append(self._round(plan))
         with obs.span("stream.stack", sink, **ids):
-            out = {key: np.stack([r[key] for r in rounds]) for key in rounds[0]}
+            if callable(out):
+                out = out(tuple((key, (k, *v.shape), v.dtype)
+                                for key, v in rounds[0].items()))
+            if out is None:
+                out = {key: np.stack([r[key] for r in rounds]) for key in rounds[0]}
+            else:
+                for key in rounds[0]:
+                    np.stack([r[key] for r in rounds], out=out[key])
         self._last_superstep_plan = plan
         return out
 
@@ -135,6 +152,7 @@ class _Staged(NamedTuple):
     counters: Optional[StreamCounters]
     meta: Any
     host_s: Optional[Dict[str, float]]
+    lease: Any
     ready: float  # perf_counter when the producer began to offer it
 
 
@@ -173,6 +191,10 @@ class DevicePrefetcher:
     dict as the item enters the ring. `__next__` exposes the dict as
     `host_s`, and `ring_depth` is the number of staged items left behind
     the one taken.
+
+    The optional `lease` hook, sampled with them, returns what the
+    consumer hands back once it is done with the batch (the driver's
+    host buffer set); `__next__` exposes it as `lease`.
     """
 
     def __init__(self, produce: Callable[[], Any], *,
@@ -181,6 +203,7 @@ class DevicePrefetcher:
                  meta: Optional[Callable[[], Any]] = None,
                  spans: Optional[Callable[[], Tuple[Optional[Dict[str, float]],
                                                     Dict[str, Any]]]] = None,
+                 lease: Optional[Callable[[], Any]] = None,
                  depth: int = 2):
         if depth < 1:
             raise ValueError("prefetch depth must be >= 1")
@@ -189,6 +212,7 @@ class DevicePrefetcher:
         self._counters = counters or (lambda: None)
         self._meta = meta or (lambda: None)
         self._spans = spans or (lambda: (None, {}))
+        self._lease = lease or (lambda: None)
         self._q: queue.Queue = _Ring(maxsize=depth)
         self._stop = threading.Event()
         self._final: Optional[Any] = None  # latched _Stop/_Raise terminal state
@@ -198,6 +222,7 @@ class DevicePrefetcher:
         self.counters: Optional[StreamCounters] = None
         self.meta: Optional[Any] = None
         self.host_s: Optional[Dict[str, float]] = None
+        self.lease: Any = None
         self.ring_depth = 0
         self._thread = threading.Thread(target=self._worker, daemon=True,
                                         name="device-prefetch")
@@ -227,10 +252,11 @@ class DevicePrefetcher:
                 snap = self._counters()
                 meta = self._meta()
                 host_s, ids = self._spans()
+                lease = self._lease()
                 staged = self._stage(item)
                 with obs.span("stream.ring_put_wait", **ids):
                     self._put_stopaware(_Staged(staged, snap, meta, host_s,
-                                                time.perf_counter()))
+                                                lease, time.perf_counter()))
         except BaseException as e:  # surface producer failures at the consumer
             if not self._put_stopaware(_Raise(e)):
                 self._undelivered = _Raise(e)
@@ -257,6 +283,7 @@ class DevicePrefetcher:
         if got.meta is not None:
             self.meta = got.meta
         self.host_s = got.host_s
+        self.lease = got.lease
         return got.batch
 
     def _drain(self) -> Optional[_Raise]:

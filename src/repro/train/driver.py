@@ -13,7 +13,8 @@ it with three stages:
 
 1. **Splitter (host thread)** — `data.pipeline.StreamingPipeline` runs the
    governed splitter of Fig. 3(c): per round it draws B + mu samples, keeps B,
-   and stacks K rounds into one superstep batch (leading K axis).
+   and stacks K rounds into one superstep batch (leading K axis), in a host
+   buffer set recycled once the superstep that read it has been fetched.
 2. **Stage (H2D overlap)** — `data.pipeline.DevicePrefetcher` stages the
    *next* superstep onto devices (sharded `jax.device_put`) from a background
    thread while the current superstep computes — the overlap of sample arrival
@@ -55,8 +56,9 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
+import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +105,42 @@ def elastic_superstep(cohort_fn: Callable, n_full: int) -> Callable:
         return jax.tree.map(put, state, sub), metrics
 
     return fn
+
+
+class _Lease(NamedTuple):
+    """A host buffer set on loan to one superstep, and whether its stack
+    landed in a recycled set."""
+
+    layout: Tuple[Tuple[str, Tuple[int, ...], np.dtype], ...]
+    bufs: Dict[str, np.ndarray]
+    reused: bool
+
+
+class _HostBuffers:
+    """Free lists of the host buffer sets the splitter stacks supersteps
+    into, keyed by a superstep's layout `((key, shape, dtype), ...)`, so
+    buckets of the adaptive-B ladder never share a set. The producer takes
+    a set and the consumer gives it back, hence the lock. A list grows only
+    when every set of its layout is in flight: at most the ring's items,
+    the one being dealt and the one being consumed."""
+
+    def __init__(self):
+        self._free: Dict[tuple, List[Dict[str, np.ndarray]]] = {}
+        self._lock = threading.Lock()
+        self.allocated = 0
+
+    def take(self, layout) -> _Lease:
+        with self._lock:
+            free = self._free.setdefault(layout, [])
+            if free:
+                return _Lease(layout, free.pop(), True)
+            self.allocated += 1
+        return _Lease(layout, {key: np.empty(shape, dtype)
+                               for key, shape, dtype in layout}, False)
+
+    def give_back(self, lease: _Lease) -> None:
+        with self._lock:
+            self._free[lease.layout].append(lease.bufs)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -241,6 +279,10 @@ class StreamingDriver:
         # host-seconds dict and span ids of the one being dealt
         self._dealt = 0
         self._dealing: Tuple[Optional[Dict[str, float]], Dict[str, int]] = (None, {})
+        # the host buffer sets supersteps are stacked into, and the lease of
+        # the one being dealt; a set goes back after its superstep's fetch
+        self._buffers = _HostBuffers()
+        self._leased: Optional[_Lease] = None
         # governor warm-up gate, per jit signature: supersteps completed at
         # each (bucket, cohort) signature (the first of a fresh signature
         # pays XLA compile time and must not feed replan or the estimator)
@@ -294,6 +336,12 @@ class StreamingDriver:
         return rates.BucketLadder.build(
             base_B, N, n_buckets=gov.n_buckets, factor=gov.bucket_factor,
             horizon_samples=self._horizon)
+
+    @property
+    def host_buffers_allocated(self) -> int:
+        """Host buffer sets allocated for the splitter's stack so far, over
+        all layouts (docs/DESIGN.md §Observability)."""
+        return self._buffers.allocated
 
     @property
     def compiled_buckets(self) -> Tuple[int, ...]:
@@ -362,17 +410,24 @@ class StreamingDriver:
         Runs on the producer thread (the consumer's when prefetch depth is
         0) and opens `_dealing`, the host-seconds dict of the superstep it
         deals and the `step` its spans carry; `_stage` and the prefetch
-        ring add to the same dict."""
+        ring add to the same dict. The rounds are stacked into a host
+        buffer set leased from `_buffers` (`_leased`), which travels with
+        the batch to the consumer."""
         sink, ids = {}, {"step": self._dealt}
         self._dealt += 1
         self._dealing = (sink, ids)
-        batch = self.pipeline.next_superstep(self.engine.superstep, sink, **ids)
+        batch = self.pipeline.next_superstep(self.engine.superstep, sink,
+                                             out=self._lease, **ids)
         if self.decentralized:
             p = self.pipeline.last_superstep_plan
             m = self.n_nodes if p.membership is None else p.membership.n_active
             with obs.span("stream.node_split", sink, **ids):
                 batch = make_node_batch(batch, m, axis=1)
         return batch
+
+    def _lease(self, layout) -> Dict[str, np.ndarray]:
+        self._leased = self._buffers.take(layout)
+        return self._leased.bufs
 
     def _batch_sharding(self) -> Optional[NamedSharding]:
         """Leading-K batches shard their second axis (global batch / node) over
@@ -408,9 +463,10 @@ class StreamingDriver:
         TrainState and the per-superstep history of metrics, throughput, and
         governor decisions. Each record also holds `host_s`, the host
         seconds of each `stream.*` span of that superstep (producer and
-        consumer; `repro.obs`), and `ring_depth`, the staged supersteps left
-        in the prefetch ring when the consumer took this one
-        (docs/DESIGN.md §Observability).
+        consumer; `repro.obs`), `ring_depth`, the staged supersteps left
+        in the prefetch ring when the consumer took this one, and
+        `stack_reused`, 1 where its stack landed in a recycled host buffer
+        set (docs/DESIGN.md §Observability).
 
         The prefetch ring persists across calls (it keeps staging between
         runs, bounded at `prefetch_depth`), so a warm-up `run()` leaves the
@@ -430,6 +486,7 @@ class StreamingDriver:
                 meta=lambda: (self.pipeline.last_superstep_plan,
                               self.pipeline.splitter_state()),
                 spans=lambda: self._dealing,
+                lease=lambda: self._leased,
                 depth=self.engine.prefetch_depth)
         for i in range(supersteps):
             with jax.profiler.StepTraceAnnotation(
@@ -462,6 +519,7 @@ class StreamingDriver:
                 counters = source.counters
                 used_plan, split_state = source.meta or (None, None)
                 dealt_s, ring_depth = source.host_s, source.ring_depth
+                lease = source.lease
             else:
                 self._dealt = step
                 staged = self._stage(self._host_superstep())
@@ -469,6 +527,7 @@ class StreamingDriver:
                 used_plan = self.pipeline.last_superstep_plan
                 split_state = self.pipeline.splitter_state()
                 dealt_s, ring_depth = self._dealing[0], 0
+                lease = self._leased
         if split_state is not None:
             self._last_splitter_state = split_state
         with obs.span("stream.dispatch", host_s, step=step):
@@ -487,12 +546,20 @@ class StreamingDriver:
                 self.state, metrics = fn(self.state, staged)
         with obs.span("stream.fetch", host_s, step=step):
             metrics = jax.device_get(metrics)  # one fetch per K rounds
+        # the executable that read the batch has finished, so its host
+        # buffers may take a later stack. Never sooner: until then the H2D
+        # copy may still read them, and on the CPU `device_put` may alias
+        # them. A superstep with no metrics fetches nothing and proves
+        # nothing: its set is dropped, not recycled
+        if jax.tree.leaves(metrics):
+            self._buffers.give_back(lease)
         wall_s = max(self.clock() - t0, 1e-12)
         # the producer's seconds for this superstep, then the consumer's
         host_s = {**(dealt_s or {}), **host_s}
         with obs.span("stream.observe", host_s, step=step):
             rec = self._observe(metrics, wall_s, counters, used_plan)
         rec["host_s"], rec["ring_depth"] = host_s, ring_depth
+        rec["stack_reused"] = int(lease.reused)
         if self._publisher is not None:
             # outside the timed window, at the plan-latch barrier: the
             # publisher's copy dispatch is async and its own governor

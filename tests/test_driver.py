@@ -7,8 +7,13 @@
   counters (samples_arrived, discards) coherent with the consumed batch
 * the closed-loop governor raises mu when the measured rate is artificially
   slowed (injected clock), and the rate inversion round-trips eq. 4
+* supersteps are stacked into recycled host buffer sets that go back only
+  after their superstep's fetch: every superstep trains on its own rows,
+  across a bucket switch too, and the sets stay at prefetch depth + 2
 """
 import dataclasses
+import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +21,9 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced
-from repro.configs.base import AveragingConfig, RunConfig, SHAPES, StreamConfig
+from repro.configs.base import (AveragingConfig, GovernorConfig, RunConfig,
+                                SHAPES, StreamConfig)
+from repro.configs.paper_pca import PCARunConfig
 from repro.core import rates
 from repro.data.lm import MarkovTokenStream
 from repro.data.pipeline import DevicePrefetcher, StreamingPipeline
@@ -520,3 +527,150 @@ def test_driver_publish_governor_skip_records_none():
     assert versions[0] == 1  # unconditional first publish
     assert versions[1:] == [None, None]
     assert pub.stats.skipped_budget == 2
+
+
+# ---------------------------------------------------------------------------
+# Recycled host buffers
+# ---------------------------------------------------------------------------
+
+D = 3
+
+
+class _IndexRows:
+    """A sample_fn whose rows name the samples they hold: stream sample i is
+    the row i * D + (0, ..., D-1). Logs the first index of every draw."""
+
+    def __init__(self):
+        self.next, self.starts = 0, []
+
+    def __call__(self, rng, n):
+        start, self.next = self.next, self.next + n
+        self.starts.append(start)
+        idx = np.arange(start, start + n, dtype=np.int32)
+        return {"x": idx[:, None] * D + np.arange(D, dtype=np.int32)}
+
+    def checksums(self, history, k):
+        """(lo, hi, sum) of the rows each recorded superstep drew: its K
+        draws, each of whose first B rows the splitter kept."""
+        out = []
+        for s, rec in enumerate(history):
+            idx = np.concatenate([np.arange(a, a + rec["bucket"])
+                                  for a in self.starts[k * s:k * (s + 1)]])
+            x = idx[:, None] * D + np.arange(D)
+            out.append((int(x.min()), int(x.max()), int(x.sum())))
+        return out
+
+
+def _checksum_superstep(state, batches):
+    """Reports (lo, hi, sum) of the whole superstep's batch in every round."""
+    x = batches["x"]
+    k = x.shape[0]
+    metrics = {"lo": x.min(), "hi": x.max(), "sum": x.sum()}
+    return ({"n": state["n"] + 1},
+            {name: jnp.full((k,), v) for name, v in metrics.items()})
+
+
+def _got(history):
+    return [tuple(int(rec["metrics"][m]) for m in ("lo", "hi", "sum"))
+            for rec in history]
+
+
+class _SlowClock:
+    """Real time, but each reading first sleeps: a consumer that takes its
+    time, so the producer fills the ring and runs ahead. Advances `dt`
+    more seconds per reading for the governor."""
+
+    def __init__(self, sleep_s=0.03, dt=0.0):
+        self.sleep_s, self.dt, self.reads = sleep_s, dt, 0
+
+    def __call__(self):
+        time.sleep(self.sleep_s)
+        self.reads += 1
+        return time.perf_counter() + self.dt * self.reads
+
+
+def _index_driver(sampler, clock, engine, stream=StreamConfig()):
+    """A driver of `_checksum_superstep` whose stage passes the host arrays
+    through: the CPU's `device_put` aliases some arrays and copies others,
+    and a staged batch that is the host buffer itself shows a set handed
+    back too early whichever it does."""
+    run_cfg = PCARunConfig(averaging=AveragingConfig("gossip", 1),
+                           stream=stream)
+    driver = StreamingDriver(
+        run_cfg, None, {"n": jnp.zeros((), jnp.int32)}, sampler,
+        superstep_fn=_checksum_superstep, n_nodes=2, batch=8,
+        engine=engine, clock=clock)
+    driver._stage = lambda batch: batch
+    return driver
+
+
+@pytest.mark.parametrize("depth", [2, 0])
+def test_recycled_host_buffers_keep_each_supersteps_rows(depth):
+    """A slow consumer lets the producer fill the ring: every superstep
+    still trains on the rows drawn for it (a set handed back before its
+    superstep's fetch would be restacked under the staged batch), after
+    warm-up every stack lands in a recycled set, and no more than depth + 2
+    sets exist."""
+    K, steps = 4, 12
+    sampler = _IndexRows()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with _index_driver(sampler, _SlowClock(), EngineConfig(
+                superstep=K, prefetch_depth=depth, replan_every=0)) as driver:
+            _, history = driver.run(steps)
+    finally:
+        sys.setswitchinterval(interval)
+    assert _got(history) == sampler.checksums(history, K)
+    sets = driver.host_buffers_allocated
+    assert sets == depth + 2 if depth else sets == 1
+    assert [rec["stack_reused"] for rec in history] == [0] * sets + [1] * (steps - sets)
+
+
+def test_bucket_switch_leases_new_buffers_and_drains_on_own_rows():
+    """An adaptive-B switch stacks the new width into a set of its own
+    layout, while the supersteps already staged at the old width drain
+    through the old bucket on the rows drawn for them."""
+    K, depth = 4, 2
+    sampler = _IndexRows()
+    stream = StreamConfig(streaming_rate=1e3, processing_rate=1e6,
+                          comms_rate=1e6)
+    gov = GovernorConfig(buckets=(8, 16), hysteresis=1, estimate_rates=False)
+    with _index_driver(sampler, _SlowClock(dt=1.0), EngineConfig(
+            superstep=K, prefetch_depth=depth, replan_every=1,
+            warmup_supersteps=0, governor=gov), stream=stream) as driver:
+        _, history = driver.run(12)
+    switch = next(i for i, rec in enumerate(history) if "bucket_switch" in rec)
+    assert history[switch]["bucket_switch"] == (8, 16)
+    buckets = [rec["bucket"] for rec in history]
+    assert 8 in buckets[switch + 1:]  # staged at the old width, drained
+    assert _got(history) == sampler.checksums(history, K)
+    wide = [rec["stack_reused"] for rec in history if rec["bucket"] == 16]
+    assert wide[0] == 0 and wide[-1] == 1  # a new layout, then recycled
+    assert driver.host_buffers_allocated <= 2 * (depth + 2)
+
+
+def test_pipeline_stacks_into_given_buffers_bit_identically():
+    """`next_superstep(k, out=...)` returns the given arrays holding the rows
+    a fresh stack holds, bit for bit, whether `out` is a dict or a function
+    of the layout; without `out` every call stacks into fresh arrays."""
+    def mk():
+        return StreamingPipeline(
+            lambda rng, n: {"x": rng.normal(size=(n, 3)).astype(np.float32),
+                            "y": rng.integers(0, 9, size=n)},
+            StreamConfig(forced_mu=2), n_nodes=2, rounds_R=1, batch=8, seed=5)
+
+    K = 3
+    fresh, given = mk(), mk()
+    a, b = fresh.next_superstep(K), fresh.next_superstep(K)
+    assert not any(np.shares_memory(a[key], b[key]) for key in a)
+    out = {key: np.empty_like(v) for key, v in a.items()}
+    got = given.next_superstep(K, out=out)
+    assert all(got[key] is out[key] for key in a)
+    assert all(got[key].tobytes() == a[key].tobytes() for key in a)
+    layouts = []
+    got = given.next_superstep(K, out=lambda lay: layouts.append(lay) or out)
+    assert layouts == [tuple((key, v.shape, v.dtype) for key, v in b.items())]
+    assert all(got[key] is out[key] for key in b)
+    assert all(got[key].tobytes() == b[key].tobytes() for key in b)
+    assert given.counters() == fresh.counters()
